@@ -5,6 +5,7 @@ from __future__ import annotations
 
 from benchmarks.common import compile_task, emit, portion_latency_s
 from benchmarks.table2_tasks import build_all
+from repro import gcv
 
 PAPER = {"b1": "5.2%", "b2": "330%", "b3_r50": "356%", "b3_r101": "356%",
          "b4": "2.3%", "b5": "2.3-20.5%", "b6": "0%"}
@@ -28,4 +29,5 @@ def run():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     run()

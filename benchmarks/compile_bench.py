@@ -180,6 +180,7 @@ def run(small: bool = True, iters: int = 3, first_run: bool = True,
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--small", action="store_true", default=True)
     ap.add_argument("--full", dest="small", action="store_false",

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 from benchmarks.common import compile_task, emit, portion_latency_s
 from benchmarks.table2_tasks import build_all
+from repro import gcv
 
 
 def run():
@@ -31,4 +32,5 @@ def run():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     run()

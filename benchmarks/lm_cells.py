@@ -5,6 +5,7 @@ from __future__ import annotations
 import os
 
 from benchmarks.common import emit
+from repro import gcv
 from repro.launch import roofline
 
 
@@ -31,4 +32,5 @@ def run(indir="experiments/dryrun_opt"):
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     run()

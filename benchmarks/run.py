@@ -5,6 +5,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro import gcv
+
 SECTIONS = [
     ("Table II / Fig.9 — GNN-CV tasks b1-b6 (modelled latency)",
      "benchmarks.table2_tasks"),
@@ -43,4 +45,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     main()

@@ -384,7 +384,7 @@ def bench_devices(graphs, options, stream, max_batch, counts, repeats):
     engine sees the same request stream and comparable buckets.  Counts
     the host cannot satisfy are skipped with a printed note — never
     silently served at a smaller mesh.  Each count's per-request results
-    are compared against the devices=1 engine's; GSPMD partitioning can
+    are compared against the devices=1 engine's; the per-shard program can
     reorder float accumulation at the last ulp on some tasks, so parity is
     a recorded max|diff| under a 1e-5 gate rather than a bitwise claim.
     """
@@ -646,4 +646,5 @@ def main():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     main()

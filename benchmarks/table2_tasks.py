@@ -4,6 +4,7 @@ paper's claimed speedup context."""
 from __future__ import annotations
 
 from benchmarks.common import compile_task, emit, plan_latency_s
+from repro import gcv
 from repro.gnncv import tasks
 
 # paper Fig. 9: GCV-Turbo speedup over GPU (RTX A5000), batch-1
@@ -38,4 +39,5 @@ def run():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     run()

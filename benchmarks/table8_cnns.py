@@ -3,6 +3,7 @@ GCV-Turbo throughput vs the paper's reported images/second."""
 from __future__ import annotations
 
 from benchmarks.common import compile_task, emit, plan_latency_s
+from repro import gcv
 from repro.gnncv import cnn_zoo
 
 PAPER_THROUGHPUT = {"c1_alexnet": 512.9, "c2_resnet50": 58.8,
@@ -35,4 +36,5 @@ def run():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     run()

@@ -4,6 +4,7 @@ reported GCV-Turbo latencies (Table XII, GCN row)."""
 from __future__ import annotations
 
 from benchmarks.common import compile_task, emit, plan_latency_s
+from repro import gcv
 from repro.gnncv import gnn_zoo
 
 # Table XII GCV-Turbo hardware latency (ms): CO, CI, PU, FL
@@ -30,4 +31,5 @@ def run():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     run()

@@ -17,6 +17,8 @@ import numpy as np
 from repro import gcv
 from repro.frontend import nn
 
+gcv.use_compile_cache()
+
 rng = np.random.default_rng(0)
 
 # -- model weights: ordinary numpy arrays closed over by the function

@@ -45,4 +45,5 @@ def main():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     main()

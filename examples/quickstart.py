@@ -11,6 +11,8 @@ from repro import gcv
 from repro.core import GraphBuilder
 from repro.core.perf_model import FPGA
 
+gcv.use_compile_cache()
+
 rng = np.random.default_rng(0)
 
 # -- a tiny GNN-CV model: conv stack -> patch-to-node DM -> message passing

@@ -11,7 +11,7 @@ import time
 import jax
 import numpy as np
 
-from repro import configs
+from repro import configs, gcv
 from repro.models.transformer import init_lm
 from repro.serve import ServeEngine
 
@@ -37,4 +37,5 @@ def main():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     main()

@@ -8,7 +8,7 @@ AdamW + cosine schedule, checkpointing every 100 steps, crash-resume.
 import argparse
 import dataclasses
 
-from repro import configs
+from repro import configs, gcv
 from repro.launch.train import train
 from repro.models.config import ModelConfig
 
@@ -47,4 +47,5 @@ def main():
 
 
 if __name__ == "__main__":
+    gcv.use_compile_cache()
     main()

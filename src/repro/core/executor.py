@@ -107,11 +107,12 @@ def build_runner(plan: ExecutionPlan, *, use_pallas: bool = False,
     ``mesh`` (a 1-D ``("data",)`` mesh) shards the **batch axis** across
     the mesh's devices: inputs/outputs carry a
     ``NamedSharding(mesh, P("data"))``, the resident weight pytree is
-    replicated (one upload per device), and the whole-program jit runs
-    SPMD.  Requires ``batch`` divisible by the device count; a one-device
-    mesh falls back to the plain single-device runner.  GSPMD partitions
-    the batch dimension without touching per-sample math, so outputs are
-    bit-for-bit identical to the single-device runner at the same batch.
+    replicated (one upload per device), and each device runs the batched
+    program on its ``batch / devices`` rows (``shard_map``).  Requires
+    ``batch`` divisible by the device count; a one-device mesh falls back
+    to the plain single-device runner.  Per-sample math is untouched, so
+    outputs match the single-device runner at the same batch up to float
+    accumulation order.
     """
     if mesh is not None and mesh.size == 1:
         mesh = None                      # the existing single-device path
@@ -168,17 +169,17 @@ def build_runner(plan: ExecutionPlan, *, use_pallas: bool = False,
 
     if weights_as_args:
         if mesh is not None:
-            # SPMD batch sharding: the resident pytree replicates (one
-            # copy per device), every input/output shards its leading
-            # batch axis over the 1-D data mesh.  Shardings are pytree
-            # prefixes over run_impl's (arrays, env) arguments.
-            replicated = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec())
-            batch_sharded = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec("data"))
-            staged = jax.jit(run_impl,
-                             in_shardings=(replicated, batch_sharded),
-                             out_shardings=batch_sharded)
+            # Batch sharding: the resident pytree replicates (one copy per
+            # device), every input/output shards its leading batch axis
+            # over the 1-D data mesh, and each device runs the batched
+            # program on its own rows.  shard_map, not automatic
+            # partitioning: XLA cannot partition a Mosaic (Pallas) kernel.
+            # Specs are pytree prefixes over run_impl's (arrays, env).
+            whole, rows = (jax.sharding.PartitionSpec(),
+                           jax.sharding.PartitionSpec("data"))
+            staged = jax.jit(jax.shard_map(
+                run_impl, mesh=mesh, in_specs=(whole, rows),
+                out_specs=rows, check_vma=False))
         else:
             staged = jax.jit(run_impl) if jit else run_impl
     else:

@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
-
 
 def pipeline_apply(stage_fn, stage_params, xs, *, mesh, axis: str = "stage",
                    param_specs=None):
@@ -71,7 +69,7 @@ def pipeline_apply(stage_fn, stage_params, xs, *, mesh, axis: str = "stage",
         mask = (idx == S - 1).astype(xs_local.dtype)
         return jax.lax.psum(ys * mask, axis)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),

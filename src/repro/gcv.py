@@ -37,6 +37,8 @@ the batch size).
 from __future__ import annotations
 
 import contextlib
+import os
+import pathlib
 from typing import Any, Callable, Mapping
 
 import jax
@@ -52,7 +54,28 @@ from repro.core.runtime.cache import cached_plan, cached_runner
 from repro.core.runtime.residency import (collect_params, plan_param_bytes,
                                           plan_slots)
 
-__all__ = ["CompiledModel", "compile", "serve", "stack_inputs", "trace_to"]
+__all__ = ["CompiledModel", "compile", "serve", "stack_inputs", "trace_to",
+           "use_compile_cache"]
+
+# The persistent compile cache's home when the environment names none: a
+# fixed directory of the checkout (listed in .gitignore).  The path is part
+# of every cache key, so it never derives from a temp name, pid or time.
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    this changes nothing; otherwise the cache goes to
+    ``COMPILE_CACHE_DIR``.  Entry points (``chip_smoke.py``, benchmarks,
+    examples) call this once at start-up; importing the library never
+    does."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def _resolve_options(options, overrides) -> CompileOptions:

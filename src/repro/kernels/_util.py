@@ -3,12 +3,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
-
-# jax ~0.6 renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernels (and their interpret-mode tests) run across the 0.4-0.6 range.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 
 def default_interpret(interpret: bool | None) -> bool:

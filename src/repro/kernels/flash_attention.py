@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._util import CompilerParams, default_interpret, pad_to, unpad
+from repro.kernels._util import default_interpret, pad_to, unpad
 
 NEG_INF = float("-inf")
 
@@ -124,7 +124,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dp), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -23,7 +23,8 @@ Block layout:
 materialized ``knn_ref`` oracle below via ``lax.top_k``, and the numpy
 ``gnncv.graphs.knn_indices`` oracle) must agree exactly:
 
-  * neighbors are the ``k`` *smallest* squared-L2 distances;
+  * neighbors are the ``k`` *smallest* squared-L2 distances, computed
+    in fp32 (``HIGHEST`` matmul precision, also on TPU);
   * output order: ascending distance, ties broken toward the **lower
     candidate index** (matching ``lax.top_k`` and stable argsort);
   * a point is never its own neighbor unless ``self_loops=True``;
@@ -44,13 +45,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._util import CompilerParams, default_interpret, pad_to
+from repro.kernels._util import default_interpret, pad_to
 
 # Index sentinel for exhausted candidate slots: larger than any real
 # column index, so min-over-achievers never picks it while real
 # candidates remain.  (Plain int — a jnp scalar here would be captured
 # as a constant by the Pallas kernel tracer.)
 _BIG_IDX = 2**30
+# Distances are fp32 products on every backend: a TPU's default
+# single-pass bf16 matmul would rank neighbors by rounded distances and
+# select a different graph than the pinned semantics.
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _knn_kernel(xi_ref, xj_ref, mj_ref, o_ref, bd_ref, bi_ref, *,
@@ -64,7 +69,8 @@ def _knn_kernel(xi_ref, xj_ref, mj_ref, o_ref, bd_ref, bi_ref, *,
     xi = xi_ref[...].astype(jnp.float32)                       # (bm, F)
     xj = xj_ref[...].astype(jnp.float32)                       # (bn, F)
     d = (jnp.sum(xi * xi, axis=1, keepdims=True)
-         - 2.0 * jnp.dot(xi, xj.T, preferred_element_type=jnp.float32)
+         - 2.0 * jnp.dot(xi, xj.T, precision=_PRECISION,
+                         preferred_element_type=jnp.float32)
          + jnp.sum(xj * xj, axis=1)[None, :])                  # (bm, bn)
     j = pl.program_id(1)
     col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
@@ -138,7 +144,7 @@ def knn(x: jax.Array, *, k: int, mask: jax.Array | None = None,
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], k), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, k), jnp.float32),
                         pltpu.VMEM((bm, k), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xp, xp, mp)
@@ -158,7 +164,8 @@ def knn_ref(x: jax.Array, *, k: int, mask: jax.Array | None = None,
     assert 1 <= k <= n, f"k={k} out of range for {n} points"
     xf = x.astype(jnp.float32)
     sq = jnp.sum(xf * xf, axis=1)
-    d = sq[:, None] - 2.0 * jnp.dot(xf, xf.T) + sq[None, :]
+    d = sq[:, None] - 2.0 * jnp.dot(xf, xf.T, precision=_PRECISION) \
+        + sq[None, :]
     if not self_loops:
         d = jnp.where(jnp.eye(n, dtype=bool), jnp.inf, d)
     if mask is not None:

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._util import CompilerParams, default_interpret, pad_to, unpad
+from repro.kernels._util import default_interpret, pad_to, unpad
 
 
 def _sddmm_kernel(bmask_ref, x_ref, y_ref, emask_ref, o_ref, acc_ref, *,
@@ -90,7 +90,7 @@ def sddmm(x: jax.Array, y: jax.Array, mask: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(bmask, xp, yp, maskp)

@@ -8,16 +8,20 @@ stay in ``channels x pixels`` layout across consecutive Conv layers AND across
 CNN->GNN transitions (channel-to-node DM becomes a no-op; patch-to-node
 becomes a transpose folded into the next matmul).
 
-This kernel fuses all k1*k2 matmuls and the shift-add merge into one pass:
-  grid = (c_out/bm, c_in/bk), c_in innermost (reduction);
-  IFM block (bk, H, W) resident in VMEM, statically unrolled loop over the
-  k1*k2 taps, each tap = static shift (jnp.roll + edge mask, VPU) feeding an
-  MXU matmul, accumulated in fp32 scratch.
+This kernel fuses all k1*k2 matmuls and the shift-add merge into one pass
+over the *flattened* plane: the IFM is laid out as (c_in, H*W), so tap
+(dy, dx) is a lane shift of the whole pixel axis by ``dy*W + dx`` (scaled
+by the dilation) — one ``pltpu.roll`` on the XLU feeding one MXU matmul,
+with no 3-D reshapes or unaligned slices.  Shifting the flat axis wraps
+row ends into the next row, but only into columns the VALID crop drops.
+
+  grid = (c_out/bm, HW/bp, c_in/bk), c_in innermost (reduction);
+  every pixel tile of bp lanes also reads the following tile, so the taps
+  see their halo (bp is at least the largest tap offset); fp32 scratch
+  accumulates the taps across the c_in blocks.
 
 The kernel computes the VALID correlation; the jit wrapper realizes SAME by
-explicit input pre-padding and stride by output subsampling (production TPU
-note: for large H*W a halo-tiled spatial grid replaces the fully-resident
-plane; paper-scale CV workloads fit VMEM after the c_in split).
+explicit input pre-padding and stride by output subsampling.
 """
 from __future__ import annotations
 
@@ -28,34 +32,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._util import CompilerParams, default_interpret, pad_to, unpad
+from repro.kernels._util import default_interpret, round_up
 
 
-def _shift_conv_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int,
-                       k1: int, k2: int, dh: int, dw: int):
-    @pl.when(pl.program_id(1) == 0)
+def _shift_conv_kernel(xa_ref, xb_ref, w_ref, o_ref, acc_ref, *, nk: int,
+                       offsets: tuple[int, ...]):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]                      # (bk, H, W)
-    _, H, W = x.shape
-    yy = jax.lax.broadcasted_iota(jnp.int32, (1, H, W), 1)
-    xx = jax.lax.broadcasted_iota(jnp.int32, (1, H, W), 2)
-    bm = acc_ref.shape[0]
-    for dy in range(k1):                # statically unrolled taps
-        for dx in range(k2):
-            # atrous taps: tap (dy, dx) reads dy*dh rows / dx*dw cols away
-            # — same shift-add merge, offsets scaled by the dilation
-            oy, ox = dy * dh, dx * dw
-            shifted = x if (oy == 0 and ox == 0) else jnp.roll(
-                x, (-oy, -ox), (1, 2))
-            shifted = jnp.where((yy < H - oy) & (xx < W - ox), shifted, 0.0)
-            km = w_ref[dy, dx]          # (bk, bm)
-            part = jnp.dot(km.T, shifted.reshape(x.shape[0], H * W),
-                           preferred_element_type=jnp.float32)
-            acc_ref[...] += part.reshape(bm, H, W)
+    bp = xa_ref.shape[1]
+    x = jnp.concatenate([xa_ref[...], xb_ref[...]], axis=1)   # (bk, 2*bp)
+    for t, off in enumerate(offsets):         # statically unrolled taps
+        shifted = x if off == 0 else pltpu.roll(x, 2 * bp - off, 1)
+        acc_ref[...] += jnp.dot(w_ref[t], shifted[:, :bp],
+                                preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(1) == nk - 1)
+    @pl.when(pl.program_id(2) == nk - 1)
     def _finalize():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -69,28 +62,37 @@ def _shift_conv_valid(x: jax.Array, w: jax.Array, *, bm: int, bk: int,
     k1, k2, _, c_out = w.shape
     dh, dw = dilation
     ke1, ke2 = (k1 - 1) * dh + 1, (k2 - 1) * dw + 1
+    offsets = tuple(dy * dh * W + dx * dw
+                    for dy in range(k1) for dx in range(k2))
+    bp = max(512, round_up(offsets[-1] + 1, 128))
     bm = min(bm, max(8, pl.next_power_of_2(c_out)))
     bk = min(bk, max(8, pl.next_power_of_2(c_in)))
-    xp = pad_to(x, (bk, 1, 1))
-    wp = pad_to(w, (1, 1, bk, bm))
-    nk = xp.shape[0] // bk
-    grid = (wp.shape[3] // bm, nk)
+    cin_p, cout_p = round_up(c_in, bk), round_up(c_out, bm)
+    P = H * W
+    n_p = -(-P // bp)
+    # one extra tile of zeros so the last pixel tile has a "next" tile
+    xf = jnp.pad(x.reshape(c_in, P),
+                 ((0, cin_p - c_in), (0, (n_p + 1) * bp - P)))
+    wt = jnp.pad(w.reshape(k1 * k2, c_in, c_out).transpose(0, 2, 1),
+                 ((0, 0), (0, cout_p - c_out), (0, cin_p - c_in)))
+    nk = cin_p // bk
     out = pl.pallas_call(
-        functools.partial(_shift_conv_kernel, nk=nk, k1=k1, k2=k2,
-                          dh=dh, dw=dw),
-        grid=grid,
+        functools.partial(_shift_conv_kernel, nk=nk, offsets=offsets),
+        grid=(cout_p // bm, n_p, nk),
         in_specs=[
-            pl.BlockSpec((bk, H, W), lambda i, k: (k, 0, 0)),
-            pl.BlockSpec((k1, k2, bk, bm), lambda i, k: (0, 0, k, i)),
+            pl.BlockSpec((bk, bp), lambda i, j, k: (k, j)),
+            pl.BlockSpec((bk, bp), lambda i, j, k: (k, j + 1)),
+            pl.BlockSpec((k1 * k2, bm, bk), lambda i, j, k: (0, i, k)),
         ],
-        out_specs=pl.BlockSpec((bm, H, W), lambda i, k: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((wp.shape[3], H, W), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, H, W), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        out_specs=pl.BlockSpec((bm, bp), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((cout_p, n_p * bp), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bp), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xp, wp)
-    return unpad(out, (c_out, H, W))[:, : H - ke1 + 1, : W - ke2 + 1]
+    )(xf, xf, wt)
+    out = out[:c_out, :P].reshape(c_out, H, W)
+    return out[:, : H - ke1 + 1, : W - ke2 + 1]
 
 
 def shift_conv2d(x: jax.Array, w: jax.Array, *, stride=1,

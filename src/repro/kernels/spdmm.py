@@ -16,15 +16,15 @@ here cost ∝ ``S1*L*N`` (padded-nnz × row width), so primitive selection
 (passes/select.py) compares ``S1*L*N`` (SpDMM) against ``S1*S2*N`` (DDMM).
 
 Block layout:
-  grid = (S1/bm, N/bn, L/bl), L innermost.
-  idx/val blocks (bm, bl); Y block (S2, bn) — full row dimension resident in
-  VMEM (production note: for very large S2 a two-level scheme with row-bucket
-  pre-sorting would tile Y; all paper graphs fit: max S2 = 16384 → 8 MiB/fp32
-  column block).
+  grid = (S1/bm, N/bn).  The (bm, L) ``idx``/``val`` blocks sit in SMEM,
+  where the kernel reads them as scalars; the Y block (S2, bn) keeps the
+  full row dimension resident in VMEM.  Each output row is the sum of L
+  single-row loads ``Y[idx[i, l], :]`` at a dynamic sublane offset, scaled
+  by the scalar ``val[i, l]`` on the VPU and accumulated in fp32 — the
+  scatter-gather pipeline as a loop over the ELL slots.  ``bn`` shrinks
+  for large S2 so the resident Y block stays within ``_Y_BLOCK_BYTES``.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -32,64 +32,74 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._util import CompilerParams, default_interpret, pad_to, unpad
+from repro.kernels._util import default_interpret, pad_to, round_up, unpad
+
+# VMEM budget for one buffer of the resident (S2, bn) fp32 Y block.
+_Y_BLOCK_BYTES = 4 << 20
 
 
-def _spdmm_kernel(idx_ref, val_ref, y_ref, o_ref, acc_ref, *, nl: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+def _spdmm_kernel(idx_ref, val_ref, y_ref, o_ref):
+    bm, nl = idx_ref.shape
+    s2, bn = y_ref.shape
 
-    bm, bl = idx_ref.shape
-    bn = y_ref.shape[1]
-    rows = jnp.take(y_ref[...], idx_ref[...].reshape(-1), axis=0)
-    rows = rows.reshape(bm, bl, bn).astype(jnp.float32)
-    acc_ref[...] += (rows * val_ref[...].astype(jnp.float32)[..., None]).sum(1)
+    def row(r, carry):
+        def slot(l, acc):
+            # padding slots carry val == 0; clamping keeps their (ignored)
+            # index inside the resident block
+            j = jnp.clip(idx_ref[r, l], 0, s2 - 1)
+            return acc + val_ref[r, l] * y_ref[pl.ds(j, 1), :]
 
-    @pl.when(pl.program_id(2) == nl - 1)
-    def _finalize():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        # Mosaic lowers a loop either fully unrolled or not at all
+        acc = jax.lax.fori_loop(0, nl, slot,
+                                jnp.zeros((1, bn), jnp.float32),
+                                unroll=nl if nl <= 16 else 1)
+        o_ref[pl.ds(r, 1), :] = acc
+        return carry
+
+    jax.lax.fori_loop(0, bm, row, 0)
 
 
 def spdmm(idx: jax.Array, val: jax.Array, y: jax.Array, *,
-          bm: int = 64, bl: int = 16, bn: int = 128,
+          bm: int = 32, bn: int = 512,
           out_dtype=None, interpret: bool | None = None) -> jax.Array:
     """ELL sparse (S1, L) @ dense (S2, N) -> (S1, N).
 
     ``idx[i, l]`` is the column (= row of ``y``) of the l-th nonzero of row i;
     padding slots must have ``val == 0`` (their ``idx`` is ignored).
+    Accumulation is fp32 whatever the input dtype.
     """
     assert idx.shape == val.shape and idx.ndim == 2
     interpret = default_interpret(interpret)
     out_dtype = out_dtype or y.dtype
     S1, L = idx.shape
     S2, N = y.shape
-    bm = min(bm, max(8, pl.next_power_of_2(S1)))
-    bl = min(bl, max(1, pl.next_power_of_2(L)))
-    bn = min(bn, max(128, pl.next_power_of_2(N)))
-    idxp = pad_to(idx, (bm, bl))
-    valp = pad_to(val, (bm, bl))
-    yp = pad_to(y, (8, bn))
-    nl = idxp.shape[1] // bl
-    grid = (idxp.shape[0] // bm, yp.shape[1] // bn, nl)
+    s2p = round_up(S2, 8)
+    bm = min(bm, round_up(S1, 8))
+    bn = min(bn, round_up(N, 128),
+             max(128, _Y_BLOCK_BYTES // (4 * s2p) // 128 * 128))
+    idxp = pad_to(idx.astype(jnp.int32), (bm, 1))
+    valp = pad_to(val.astype(jnp.float32), (bm, 1))
+    yp = pad_to(y.astype(jnp.float32), (8, bn))
+    grid = (idxp.shape[0] // bm, yp.shape[1] // bn)
 
     out = pl.pallas_call(
-        functools.partial(_spdmm_kernel, nl=nl),
+        _spdmm_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bl), lambda i, j, l: (i, l)),
-            pl.BlockSpec((bm, bl), lambda i, j, l: (i, l)),
-            pl.BlockSpec((yp.shape[0], bn), lambda i, j, l: (0, j)),
+            pl.BlockSpec((bm, L), lambda i, j: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((bm, L), lambda i, j: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((s2p, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((idxp.shape[0], yp.shape[1]),
-                                       out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(idxp, valp, yp)
-    return unpad(out, (S1, N))
+    return unpad(out, (S1, N)).astype(out_dtype)
 
 
 def dense_to_ell(x: np.ndarray | jax.Array,

@@ -166,8 +166,6 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax <= 0.5 returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = hlo_analysis.collective_bytes(hlo)
     pc = hlo_analysis.program_costs(hlo)      # trip-count weighted

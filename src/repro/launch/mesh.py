@@ -4,14 +4,15 @@ Functions, not module-level constants — importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before any jax import; smoke
 tests see 1 device).
 
-Every builder degrades gracefully when the host has fewer devices than the
+The LM builders degrade gracefully when the host has fewer devices than the
 requested shape: the largest fitting mesh is built instead (later axes —
 the model/TP axes — keep their extent first, since those shard actual
 tensors; leading DP axes give way), a ``UserWarning`` names the
 substitution, and with tracing on an ``obs.instant("mesh.degraded")``
 marker records it in the timeline.  A 1-host smoke test therefore gets a
 (1, 1) mesh from ``make_host_mesh((2, 4))`` rather than a ``reshape``
-error.
+error.  The serving data mesh (``make_data_mesh``) does not degrade: a
+server asked for N devices either gets N or fails.
 """
 from __future__ import annotations
 
@@ -80,10 +81,10 @@ def make_data_mesh(devices=None):
     """1-D ``("data",)`` mesh for batch-axis data parallelism — what
     ``gcv.compile(devices=)`` / ``gcv.serve(devices=)`` shard over.
 
-    ``devices`` is ``None`` (every visible device), an int (the first N,
-    degrading with a warning when fewer exist), or an explicit sequence of
-    ``jax.Device``s.  A pre-built ``Mesh`` goes through ``as_data_mesh``
-    instead.
+    ``devices`` is ``None`` (every visible device), an int (the first N;
+    asking for more than are visible raises ``ValueError``), or an explicit
+    sequence of ``jax.Device``s.  A pre-built ``Mesh`` goes through
+    ``as_data_mesh`` instead.
     """
     if devices is None:
         devs = list(jax.devices())
@@ -91,12 +92,9 @@ def make_data_mesh(devices=None):
         assert devices >= 1, f"devices must be >= 1, got {devices}"
         avail = jax.devices()
         if devices > len(avail):
-            warnings.warn(
+            raise ValueError(
                 f"requested {devices} devices but only {len(avail)} "
-                f"exist; using all {len(avail)}", UserWarning, stacklevel=2)
-            obs.instant("mesh.degraded", cat="launch",
-                        requested=[devices], got=[len(avail)],
-                        devices=len(avail))
+                f"{avail[0].platform} device(s) are visible")
         devs = list(avail[:devices])
     else:
         devs = list(devices)

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import axis_size, shard_map
 from repro.models.layers import init_linear, init_mlp, mlp_apply
 
 
@@ -109,7 +108,7 @@ def _moe_a2a_local(params, x, cfg, axis: str, dp_axes=("data",)):
     """Runs per-device under shard_map. x: (B_loc, S_loc, d)."""
     mo = cfg.moe
     d = cfg.d_model
-    M = axis_size(axis)
+    M = jax.lax.axis_size(axis)
     e_loc = mo.n_experts // M
     t = x.reshape(-1, d)
     T = t.shape[0]
@@ -176,7 +175,7 @@ def moe_a2a(params, x, cfg, *, mesh, dp_axes=("data",), model_axis="model"):
     if mo.n_shared:
         espec["shared"] = jax.tree.map(lambda _: P(), params["shared"])
     fn = partial(_moe_a2a_local, cfg=cfg, axis=model_axis, dp_axes=dp_axes)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         lambda p, xx: fn(p, xx),
         mesh=mesh,
         in_specs=(espec, P(dp_axes, model_axis, None)),
@@ -197,7 +196,7 @@ def _moe_gathered_local(params, x, cfg, axis: str, dp_axes=("data",)):
     ``axis``; expert weights sharded on dim 0."""
     mo = cfg.moe
     d = cfg.d_model
-    M = axis_size(axis)
+    M = jax.lax.axis_size(axis)
     ridx = jax.lax.axis_index(axis)
     e_loc = mo.n_experts // M
     t = x.reshape(-1, d)
@@ -248,7 +247,7 @@ def moe_gathered(params, x, cfg, *, mesh, dp_axes=("data",),
         espec["shared"] = jax.tree.map(lambda _: P(), params["shared"])
     fn = partial(_moe_gathered_local, cfg=cfg, axis=model_axis,
                  dp_axes=dp_axes)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         lambda p, xx: fn(p, xx),
         mesh=mesh,
         in_specs=(espec, P(dp_axes, None, None)),
@@ -273,9 +272,9 @@ def _moe_gathered2d_local(params, x, cfg, model_axis: str, fsdp_axis):
     """
     mo = cfg.moe
     d = cfg.d_model
-    M = axis_size(model_axis)
+    M = jax.lax.axis_size(model_axis)
     ridx = jax.lax.axis_index(model_axis)
-    D = axis_size(fsdp_axis) if isinstance(fsdp_axis, str) else 1
+    D = jax.lax.axis_size(fsdp_axis) if isinstance(fsdp_axis, str) else 1
     e_loc = mo.n_experts // M
     t = x.reshape(-1, d)
     T = t.shape[0]
@@ -337,7 +336,7 @@ def moe_gathered2d(params, x, cfg, *, mesh, dp_axes=("data",),
         espec["shared"] = jax.tree.map(lambda _: P(), params["shared"])
     fn = partial(_moe_gathered2d_local, cfg=cfg, model_axis=model_axis,
                  fsdp_axis=fsdp)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         lambda p, xx: fn(p, xx),
         mesh=mesh,
         in_specs=(espec, P(None, None, None)),

@@ -346,3 +346,23 @@ def test_no_deprecated_entry_points_in_repo():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     assert mod.offences() == []
+
+
+def test_use_compile_cache_env_first_then_fixed_checkout_dir(
+        monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX; unset, the
+    cache goes to one fixed directory at the root of the checkout."""
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert gcv.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = gcv.use_compile_cache()
+        assert path == str(gcv.COMPILE_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        root = gcv.COMPILE_CACHE_DIR.parent
+        assert (root / "src" / "repro" / "gcv.py").is_file()
+        assert ".jax_cache/" in (root / ".gitignore").read_text()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -67,8 +67,11 @@ def test_degradation_emits_trace_marker():
 
 # ----------------------------------------------------------- data mesh ----
 def test_data_mesh_int_degrades_with_warning():
-    with pytest.warns(UserWarning, match="only"):
-        mesh = lm.make_data_mesh(2 * AVAIL)
+    """Asking for more devices than are visible no longer degrades: a
+    serving mesh never runs on fewer devices than asked, so it raises."""
+    with pytest.raises(ValueError, match="visible"):
+        lm.make_data_mesh(2 * AVAIL)
+    mesh = lm.make_data_mesh(AVAIL)
     assert mesh.size == AVAIL
     assert tuple(mesh.axis_names) == ("data",)
 

@@ -5,7 +5,7 @@ needing a real mesh runs in a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — same idiom as
 ``test_distributed.py``.
 
-Parity note: GSPMD compiles a *per-shard* program, whose fusion and
+Parity note: each device runs a *per-shard* program, whose fusion and
 vectorization on CPU can reorder float accumulation at the last ulp on
 some tasks (observed ~4e-7 on b4).  The parity matrix therefore asserts
 ``allclose(rtol=1e-5, atol=1e-6)`` — the documented tolerance the
