@@ -265,8 +265,7 @@ class PR3BaselineEngine(GNNCVServeEngine):
     def __init__(self, graphs, **kw):
         super().__init__(graphs, pipeline_depth=1, residency=False, **kw)
 
-    @staticmethod
-    def _stack(samples):
+    def _stack(self, samples, info):
         keys = samples[0].keys()
         return {k: jnp.stack([jnp.asarray(s[k]) for s in samples])
                 for k in keys}

@@ -285,14 +285,19 @@ def random_inputs(plan: ExecutionPlan, seed: int = 0,
     return out
 
 
+def stack_host(samples: list[dict]) -> dict:
+    """Stack per-sample input dicts into one batched dict of host (NumPy)
+    arrays, one ``np.stack`` per input name."""
+    assert samples, "empty batch"
+    return {k: np.stack([np.asarray(s[k]) for s in samples])
+            for k in samples[0]}
+
+
 def stack_inputs(samples: list[dict]) -> dict:
     """Stack per-sample input dicts into one batched input dict.
 
-    Stacking happens on the host (``np.stack``) so each input name costs
+    Stacking happens on the host (``stack_host``) so each input name costs
     one device transfer for the whole batch — the previous form staged N
     per-sample device puts and stacked on device, paying N dispatches per
     input name per batch."""
-    assert samples, "empty batch"
-    keys = samples[0].keys()
-    return {k: jnp.asarray(np.stack([np.asarray(s[k]) for s in samples]))
-            for k in keys}
+    return {k: jnp.asarray(v) for k, v in stack_host(samples).items()}

@@ -8,7 +8,9 @@ self-measuring end to end, with zero dependencies and zero cost when off:
     (residency upload bytes, AOT warmup per (task, bucket)) and the
     serving lifecycle (dispatch/harvest batches, one retroactive span per
     request), exportable as Chrome/Perfetto trace-event JSON
-    (``gcv.trace_to(path)`` / ``obs.export_chrome_trace``);
+    (``gcv.trace_to(path)`` / ``obs.export_chrome_trace``); live spans
+    also land, under their names, on a ``jax.profiler`` trace taken while
+    the tracer is enabled, on the device operations' clock;
   * **metrics** (``obs.MetricsRegistry`` / the process-global
     ``obs.metrics()``) — counters, gauges, and zero-safe histograms that
     ``GNNCVServeEngine.stats()``, ``CompiledModel.stats()`` and the

@@ -10,10 +10,17 @@ Design constraints, in order:
   * **zero cost when off** — the tracer is disabled by default; the
     module-level ``span()`` helper returns a shared no-op object without
     allocating, so instrumented hot paths pay one attribute read;
-  * **zero dependencies** — stdlib only (``time``/``threading``/``json``);
-    this module is the one place in the repo allowed to call
+  * **stdlib at import** — ``time``/``threading``/``json`` only; this
+    module is the one place in the repo allowed to call
     ``time.perf_counter`` for timing (``tools/lint_deprecated.py`` gates
     everything else onto ``obs.now()``/``obs.span()``);
+  * **one timeline with the device** — while the tracer is enabled every
+    live span also enters a ``jax.profiler.TraceAnnotation`` of the same
+    name (no args: the profiler folds them into the event name), so under
+    a ``jax.profiler`` trace the spans land on the host plane of the
+    ``.xplane.pb``, on the clock of the device's operations.  ``jax`` is
+    imported on the first enabled span, never while the tracer is off;
+    retroactive ``complete`` events have no live region to annotate;
   * **nesting without bookkeeping** — spans track their parent through a
     per-thread stack, so the Chrome flame graph comes out right even when
     compile spans nest three deep, and tests can assert on ``.parent``.
@@ -58,13 +65,25 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotation(name: str):
+    """The profiler annotation a live span enters (imported lazily)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
+
+
 class Span:
     """One timed region.  Context manager; ``set(**attrs)`` adds attributes
     mid-flight (op counts, byte totals) that are only known once the work
-    has run."""
+    has run.  Entering it also enters a profiler annotation of its name."""
 
     __slots__ = ("name", "cat", "args", "t0", "dur", "parent", "tid",
-                 "_tracer")
+                 "_tracer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: dict[str, Any]):
@@ -83,11 +102,14 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self.t0 = now()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.dur = now() - self.t0
+        self._ann.__exit__(*exc)
         self._tracer._pop(self)
         return False
 

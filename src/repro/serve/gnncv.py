@@ -78,8 +78,15 @@ histogram — zero-safe: percentiles are ``None`` until a request has been
 harvested), and with tracing on (``gcv.trace_to(path)``) each dispatch and
 harvest is a span carrying batch id / bucket / pad count, plus one
 retroactive span per request from submit to harvest — a serve run opens in
-``chrome://tracing``.  Tracing is off by default and costs one attribute
-read per dispatch.
+``chrome://tracing``.  Inside them live spans split the host work —
+``serve.schedule``, ``serve.stack``, ``serve.transfer``, ``serve.launch``
+at dispatch; ``serve.wait``, ``serve.copy``, ``serve.unpack`` at harvest —
+and land on a ``jax.profiler`` trace's host plane too, on the device
+operations' clock.  Tracing is off by default and costs one attribute
+read per span.  Always on: every call into the runtime (transfer, launch,
+the readiness probe, wait, copy) is timed, and one slower than
+``STALL_MS`` counts as a stall (``stats()['stalls']``; a ``serve.stall``
+event when tracing).
 """
 from __future__ import annotations
 
@@ -87,13 +94,21 @@ import dataclasses
 import itertools
 from collections import deque
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
 from repro.core.compiler import CompileOptions
-from repro.core.executor import stack_inputs
+from repro.core.executor import stack_host
 from repro.core.ir import Graph
 from repro.core.plan import ExecutionPlan
+
+# A runtime call slower than this is a stall: about 3x the device time of
+# the longest batch the chip benchmark serves (8 DGCNN clouds, ~17 ms).
+STALL_MS = 50.0
+# the serving loop's calls into the runtime, each timed against STALL_MS
+RUNTIME_CALLS = ("transfer", "launch", "ready", "wait", "copy")
 
 
 @dataclasses.dataclass
@@ -274,7 +289,8 @@ class GNNCVServeEngine:
         self._c_misses = self.metrics.counter("deadline_misses")
         self._c_shed = self.metrics.counter("shed")
         self._c_expired = self.metrics.counter("expired_at_submit")
-        self._g_queue = self.metrics.gauge("queue_depth")
+        self._c_stalls = self.metrics.counter("stalls")
+        self._h_stall = self.metrics.histogram("stall_ms")
         self.metrics.gauge("pipeline_depth").set(self._depth)
         self._plan_cost: dict[str, float] = {}
         self._t_first_dispatch: float | None = None
@@ -397,8 +413,6 @@ class GNNCVServeEngine:
             self._finish_unserved(req, t)
             return req
         self.queues[task].append(req)
-        self._g_queue.set(self.pending())
-        self.metrics.gauge(f"queue_depth.{task}").set(len(self.queues[task]))
         return req
 
     def _finish_unserved(self, req: TaskRequest, now: float) -> None:
@@ -431,9 +445,6 @@ class GNNCVServeEngine:
                 else:
                     keep.append(r)
             self.queues[task] = keep
-            self.metrics.gauge(f"queue_depth.{task}").set(len(keep))
-        if shed:
-            self._g_queue.set(self.pending())
         return shed
 
     def pending(self) -> int:
@@ -477,7 +488,6 @@ class GNNCVServeEngine:
             }
         self.metrics.gauge("pending").set(self.pending())
         self.metrics.gauge("inflight").set(self.inflight())
-        self._g_queue.set(self.pending())
         goodput = self._c_goodput.value
         misses = self._c_misses.value
         # every terminal request lands in exactly one of goodput/misses
@@ -509,6 +519,11 @@ class GNNCVServeEngine:
                 "deadline_misses": misses,
                 "shed": self._c_shed.value,
                 "expired_at_submit": self._c_expired.value,
+                "stalls": self._c_stalls.value,
+                "stall_ms_total": self._h_stall.total,
+                "stalls_by_call": {
+                    call: self.metrics.counter(f"stalls.{call}").value
+                    for call in RUNTIME_CALLS},
                 "deadline_miss_rate": (misses / finished if finished
                                        else None),
                 "goodput_req_per_s": (goodput / elapsed if elapsed
@@ -589,12 +604,35 @@ class GNNCVServeEngine:
     def _runner(self, task: str, bucket: int):
         return self.models[task].batched(bucket, jit=self.jit)
 
-    @staticmethod
-    def _stack(samples: list[dict]) -> dict:
-        """Batch assembly hook (host-side ``np.stack``, one device
-        transfer per input name); benchmarks override it to reconstruct
-        legacy serving paths."""
-        return stack_inputs(samples)
+    def _stack(self, samples: list[dict], info: _BatchInfo) -> dict:
+        """Batch assembly hook: one host-side ``np.stack`` per input name
+        (``serve.stack``), then one device transfer each
+        (``serve.transfer``, a timed runtime call); benchmarks override it
+        to reconstruct legacy serving paths."""
+        with obs.span("serve.stack", cat="serve"):
+            host = stack_host(samples)
+        t0 = obs.now()
+        with obs.span("serve.transfer", cat="serve"):
+            stacked = {k: jnp.asarray(v) for k, v in host.items()}
+        self._runtime_call("transfer", t0, info)
+        return stacked
+
+    def _runtime_call(self, call: str, t0: float, info: _BatchInfo
+                      ) -> float:
+        """Close the timing of one call into the runtime begun at ``t0``
+        (an ``obs.now()`` reading) and return now: a call slower than
+        ``STALL_MS`` counts under ``stalls`` and ``stalls.<call>``, is
+        observed by the ``stall_ms`` histogram, and with tracing on is a
+        ``serve.stall`` event naming the call and the batch."""
+        t1 = obs.now()
+        ms = (t1 - t0) * 1e3
+        if ms > STALL_MS:
+            self._c_stalls.inc()
+            self.metrics.counter(f"stalls.{call}").inc()
+            self._h_stall.observe(ms)
+            obs.complete("serve.stall", t0, t1, cat="serve", call=call,
+                         batch_id=info.batch_id, bucket=info.bucket)
+        return t1
 
     # ------------------------------------------------------------ warmup --
     def warmup(self, tasks=None, buckets=None) -> set[tuple[str, int]]:
@@ -645,6 +683,8 @@ class GNNCVServeEngine:
         ``(j % ndev) * (bucket // ndev) + j // ndev``.  Pad positions
         (``take..bucket-1``) thereby spread (near-)evenly across devices
         instead of piling onto the last shard."""
+        if not self.pending():
+            return 0           # no span: poll spins here between arrivals
         with obs.span("serve.schedule", cat="serve",
                       policy=self.scheduler.name, pending=self.pending(),
                       inflight=len(self._inflight),
@@ -662,8 +702,6 @@ class GNNCVServeEngine:
         assert 1 <= take <= len(queue) and take <= bucket <= self.max_batch, \
             f"scheduler decision {d} invalid for queue of {len(queue)}"
         reqs = [queue.popleft() for _ in range(take)]
-        self._g_queue.set(self.pending())
-        self.metrics.gauge(f"queue_depth.{task}").set(len(queue))
         padded = reqs + [reqs[-1]] * (bucket - take)
         ndev = self._ndev
         rows = tuple((j % ndev) * (bucket // ndev) + j // ndev
@@ -680,8 +718,11 @@ class GNNCVServeEngine:
                           bucket - take, t0, devices=ndev, rows=rows,
                           shard_n=shard_n, pad_per_dev=pad_per_dev)
         run = self._runner(task, bucket)
-        outs = run(**self._stack(samples))
-        t1 = obs.now()
+        stacked = self._stack(samples, info)
+        t_launch = obs.now()
+        with obs.span("serve.launch", cat="serve"):
+            outs = run(**stacked)
+        t1 = self._runtime_call("launch", t_launch, info)
         if obs.enabled():
             # one retroactive dispatch span per device track (exactly one
             # on a single-device engine): the global batch identity plus
@@ -721,8 +762,12 @@ class GNNCVServeEngine:
             if dq:
                 dq.popleft()
         t0 = obs.now()
-        mats = [np.asarray(o) for o in outs]
-        done = obs.now()
+        with obs.span("serve.wait", cat="serve"):
+            jax.block_until_ready(outs)    # waiting, told apart from copying
+        t_copy = self._runtime_call("wait", t0, info)
+        with obs.span("serve.copy", cat="serve"):
+            mats = [np.asarray(o) for o in outs]
+        done = self._runtime_call("copy", t_copy, info)
         traced = obs.enabled()
         if traced:
             # one retroactive harvest span per device track (exactly one
@@ -733,40 +778,41 @@ class GNNCVServeEngine:
                              bucket=info.bucket, n=len(reqs), device=d,
                              shard_n=(info.shard_n[d] if info.shard_n
                                       else len(reqs)))
-        # measured service time of this (task, bucket) — the scheduler's
-        # warm estimate (estimate_batch_seconds) reads its recent mean
-        self.metrics.histogram(
-            f"service_ms.{info.task}.b{info.bucket}").observe(
-            (done - info.t_dispatch) * 1e3)
-        rows = info.rows
-        for i, req in enumerate(reqs):
-            row = rows[i] if rows else i    # undo the shard placement
-            req.result = tuple(np.array(m[row]) for m in mats)
-            req.done = True
-            req.t_done = done
-            sojourn_ms = (done - req.t_submit) * 1e3
-            self._h_sojourn.observe(sojourn_ms)
-            self._h_sojourn_recent.observe(sojourn_ms)
-            self._h_queue.observe((req.t_dispatch - req.t_submit) * 1e3)
-            self.metrics.counter(f"task.{req.task}.completed").inc()
-            if req.deadline_s is not None and done > req.deadline_s:
-                req.missed_deadline = True
-                self._c_misses.inc()
-                self.metrics.counter(
-                    f"task.{req.task}.deadline_misses").inc()
-            else:
-                self._c_goodput.inc()   # deadline-free completions count
-            if traced:
-                # retroactive per-request span: the whole sojourn, from
-                # enqueue through this harvest
-                obs.complete("request", req.t_submit, done, cat="serve",
-                             rid=req.rid, task=req.task,
-                             batch_id=info.batch_id, bucket=info.bucket,
-                             pad=info.pad, device=i % info.devices,
-                             queued_ms=round(
-                                 (req.t_dispatch - req.t_submit) * 1e3, 3))
-        self._c_completed.inc(len(reqs))
-        self._t_last_harvest = done
+        with obs.span("serve.unpack", cat="serve"):
+            # measured service time of this (task, bucket) — the scheduler's
+            # warm estimate (estimate_batch_seconds) reads its recent mean
+            self.metrics.histogram(
+                f"service_ms.{info.task}.b{info.bucket}").observe(
+                (done - info.t_dispatch) * 1e3)
+            rows = info.rows
+            for i, req in enumerate(reqs):
+                row = rows[i] if rows else i    # undo the shard placement
+                req.result = tuple(np.array(m[row]) for m in mats)
+                req.done = True
+                req.t_done = done
+                sojourn_ms = (done - req.t_submit) * 1e3
+                self._h_sojourn.observe(sojourn_ms)
+                self._h_sojourn_recent.observe(sojourn_ms)
+                self._h_queue.observe((req.t_dispatch - req.t_submit) * 1e3)
+                if req.deadline_s is not None and done > req.deadline_s:
+                    req.missed_deadline = True
+                    self._c_misses.inc()
+                    self.metrics.counter(
+                        f"task.{req.task}.deadline_misses").inc()
+                else:
+                    self._c_goodput.inc()   # deadline-free completions count
+                if traced:
+                    # retroactive per-request span: the whole sojourn, from
+                    # enqueue through this harvest
+                    obs.complete("request", req.t_submit, done, cat="serve",
+                                 rid=req.rid, task=req.task,
+                                 batch_id=info.batch_id, bucket=info.bucket,
+                                 pad=info.pad, device=i % info.devices,
+                                 queued_ms=round(
+                                     (req.t_dispatch - req.t_submit) * 1e3, 3))
+            self._c_completed.inc(len(reqs))
+            self.metrics.counter(f"task.{info.task}.completed").inc(len(reqs))
+            self._t_last_harvest = done
         return len(reqs)
 
     # -------------------------------------------------------------- step --
@@ -812,8 +858,11 @@ class GNNCVServeEngine:
         as ready, which only costs an early materialize."""
         if not self._inflight:
             return False
-        _, outs, _ = self._inflight[0]
-        return all(getattr(o, "is_ready", lambda: True)() for o in outs)
+        _, outs, info = self._inflight[0]
+        t0 = obs.now()
+        ready = all(getattr(o, "is_ready", lambda: True)() for o in outs)
+        self._runtime_call("ready", t0, info)
+        return ready
 
     def poll(self, *, draining: bool = False) -> tuple[int, int]:
         """One non-blocking pump of the continuous-batching loop; returns

@@ -279,3 +279,196 @@ def test_check_trace_tool_validates_artifacts(tmp_path):
     assert problems and "no.such.span" in problems[0]
     assert check_trace.check(str(tmp_path / "missing.json"), ["x"]) \
         == [f"{tmp_path / 'missing.json'}: missing"]
+
+
+# ------------------------------------------------- profiler bridge ------
+def _profiled(tmp_path, body):
+    """Run ``body`` under a CPU ``jax.profiler`` trace; return the host
+    plane's events as ``(name, start_ns, end_ns)``."""
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_live_spans_land_on_the_profiler_trace_nested(tmp_path):
+    def body():
+        obs.get_tracer().enable()
+        with obs.span("serve.outer", cat="serve", k=1):
+            with obs.span("serve.inner", cat="serve"):
+                pass
+        t0 = obs.now()
+        obs.complete("serve.after", t0, t0 + 1e-3)     # retroactive
+    host = _profiled(tmp_path, body)
+    by_name = {name: (a, b) for name, a, b in host
+               if name.startswith("serve.")}
+    # plain names (no args folded in), retroactive events not bridged
+    assert set(by_name) == {"serve.outer", "serve.inner"}
+    (o0, o1), (i0, i1) = by_name["serve.outer"], by_name["serve.inner"]
+    assert o0 <= i0 <= i1 <= o1
+    assert {s.name for s in obs.get_tracer().spans} == set(by_name)
+
+
+def test_disabled_tracer_makes_no_profiler_annotation(monkeypatch):
+    from repro.obs import trace
+    made = []
+    monkeypatch.setattr(trace, "_annotation",
+                        lambda name: made.append(name) or obs.NOOP_SPAN)
+    assert obs.span("serve.launch") is obs.NOOP_SPAN
+    assert obs.get_tracer().span("serve.launch") is obs.NOOP_SPAN
+    with obs.span("serve.launch"):
+        pass
+    assert made == []
+    obs.get_tracer().enable()
+    with obs.span("serve.launch"):
+        pass
+    assert made == ["serve.launch"]
+
+
+def _abs(e):
+    t = obs.get_tracer()
+    return t.epoch + e["ts"] / 1e6, t.epoch + (e["ts"] + e["dur"]) / 1e6
+
+
+def test_traced_run_splits_dispatch_and_harvest_into_live_spans():
+    clear_caches()
+    eng = GNNCVServeEngine({"b6": build_task("b6", small=True)},
+                           options=OPTS, max_batch=4)
+    for s in range(6):
+        eng.submit("b6", **request_inputs(eng.plans["b6"], seed=s))
+    with obs.telemetry(True):
+        assert eng.run() == 6                     # batches of 4 and 2
+    tracer = obs.get_tracer()
+    events = sorted(tracer.events, key=lambda e: e["ts"])
+    live = sorted(tracer.spans, key=lambda s: s.t0)
+    eps = 1e-6
+
+    def inside(names, parent):
+        spans = {n: [s for s in live if s.name == n] for n in names}
+        parents = [e for e in events if e["name"] == parent]
+        assert len(parents) == 2
+        for n in names:
+            assert len(spans[n]) == len(parents), n
+            for sp, e in zip(spans[n], parents):
+                a, b = _abs(e)
+                assert a - eps <= sp.t0 and sp.t0 + sp.dur <= b + eps, n
+        return parents
+
+    inside(("serve.stack", "serve.transfer", "serve.launch"),
+           "serve.dispatch")
+    harvests = inside(("serve.wait", "serve.copy"), "serve.harvest")
+    # unpack follows the copy: the harvest event keeps its end at the copy
+    unpack = [s for s in live if s.name == "serve.unpack"]
+    assert len(unpack) == 2
+    for sp, e in zip(unpack, harvests):
+        assert sp.t0 >= _abs(e)[1] - eps
+    # the retroactive events keep their args
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    # (a cold runner's first launch compiles, which may count a stall)
+    assert set(by) - {"serve.stall"} == {"serve.dispatch", "serve.harvest",
+                                         "request"}
+    assert set(by["serve.dispatch"][0]["args"]) == {
+        "task", "bucket", "batch_id", "n", "pad", "device", "shard_n",
+        "shard_pad"}
+    assert set(by["serve.harvest"][0]["args"]) == {
+        "task", "batch_id", "bucket", "n", "device", "shard_n"}
+    assert len(by["request"]) == 6
+    assert set(by["request"][0]["args"]) == {
+        "rid", "task", "batch_id", "bucket", "pad", "device", "queued_ms"}
+    # a request ends where its harvest event ends
+    first = by["serve.harvest"][0]
+    assert {_abs(r)[1] for r in by["request"]
+            if r["args"]["batch_id"] == first["args"]["batch_id"]} \
+        == {_abs(first)[1]}
+
+
+def test_empty_dispatch_returns_before_the_schedule_span():
+    clear_caches()
+    eng = GNNCVServeEngine({"b6": build_task("b6", small=True)},
+                           options=OPTS, max_batch=2)
+    with obs.telemetry(True):
+        assert eng.dispatch() == 0
+        assert eng.poll() == (0, 0)
+    assert obs.get_tracer().spans == []
+
+
+class _StubOut:
+    """A batch output whose readiness probe or host copy takes ``sleep``
+    seconds."""
+
+    def __init__(self, rows, call, sleep):
+        self.rows, self.call, self.sleep = rows, call, sleep
+
+    def _maybe_sleep(self, call):
+        if call == self.call:
+            import time
+            time.sleep(self.sleep)
+
+    def is_ready(self):
+        self._maybe_sleep("ready")
+        return True
+
+    def block_until_ready(self):
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self._maybe_sleep("copy")
+        return np.zeros((self.rows, 3), np.float32)
+
+
+@pytest.mark.parametrize("call,sleep_s,stalls", [
+    ("ready", 0.060, 1), ("copy", 0.060, 1),
+    ("ready", 0.005, 0), ("copy", 0.005, 0)])
+def test_a_slow_runtime_call_counts_one_stall(call, sleep_s, stalls):
+    from repro.serve.gnncv import RUNTIME_CALLS, STALL_MS
+    assert sleep_s * 1e3 > STALL_MS if stalls else sleep_s * 1e3 < STALL_MS
+    clear_caches()
+    eng = GNNCVServeEngine({"b6": build_task("b6", small=True)},
+                           options=OPTS, max_batch=2)
+    eng._runner = lambda task, bucket: (
+        lambda **inputs: (_StubOut(bucket, call, sleep_s),))
+    eng.submit("b6", **request_inputs(eng.plans["b6"], seed=0))
+    with obs.telemetry(True):
+        assert eng.dispatch() == 1
+        assert eng.poll() == (0, 1)
+    st = eng.stats()
+    assert st["stalls"] == stalls
+    assert st["stalls_by_call"] == {
+        c: (stalls if c == call else 0) for c in RUNTIME_CALLS}
+    stall_events = [e for e in obs.get_tracer().events
+                    if e["name"] == "serve.stall"]
+    assert len(stall_events) == stalls
+    if stalls:
+        assert st["stall_ms_total"] >= sleep_s * 1e3
+        assert stall_events[0]["args"] == {"call": call, "batch_id": 0,
+                                           "bucket": 1}
+        assert stall_events[0]["dur"] >= sleep_s * 1e6
+    else:
+        assert st["stall_ms_total"] == 0.0
+
+
+def test_stats_report_stalls_and_no_queue_depth_gauges():
+    clear_caches()
+    eng = GNNCVServeEngine({"b6": build_task("b6", small=True)},
+                           options=OPTS, max_batch=2)
+    st = eng.stats()
+    assert st["stalls"] == 0 and st["stall_ms_total"] == 0.0
+    assert set(st["stalls_by_call"]) == {"transfer", "launch", "ready",
+                                         "wait", "copy"}
+    for s in range(3):
+        eng.submit("b6", **request_inputs(eng.plans["b6"], seed=s))
+    assert eng.run() == 3
+    assert eng.stats()["per_task"]["b6"]["completed"] == 3
+    assert not [n for n in eng.metrics.snapshot() if "queue_depth" in n]

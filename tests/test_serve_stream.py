@@ -332,8 +332,10 @@ def test_dispatch_emits_schedule_span(graphs, tmp_path):
     import sys
     events = json.loads(path.read_text())["traceEvents"]
     sched = [e for e in events if e["name"] == "serve.schedule"]
-    assert len(sched) >= 2                 # one per dispatch() call
-    hit = next(e for e in sched if "task" in e["args"])
+    # one per dispatch() with work queued: run()'s later calls find every
+    # queue empty and return before opening a span
+    assert len(sched) == 1
+    hit = sched[0]
     assert hit["args"]["policy"] == "slo"
     assert (hit["args"]["task"], hit["args"]["take"],
             hit["args"]["bucket"]) == ("b1", 2, 2)
